@@ -66,8 +66,7 @@ def _policy(dp, *, train: bool = False):
 
 
 def _cost(compiled) -> dict:
-    from repro.utils.compat import cost_analysis
-    c = cost_analysis(compiled)
+    c = compiled.cost_analysis()
     colls = collective_schedule(compiled.as_text())
     return {
         "flops": float(c.get("flops", 0.0)),

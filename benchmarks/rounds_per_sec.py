@@ -514,18 +514,19 @@ def _bench_multihost(args) -> float:
     import subprocess
     import sys
 
-    import jax
-
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]
-    # split the PARENT's device pool (whatever XLA_FLAGS it honored)
-    # across the workers so this row benches the same global device
-    # count as the in-process rows it is read against
-    devices = max(1, len(jax.devices()) // args.processes)
+    # split the PARENT's forced CPU device pool (read from XLA_FLAGS, not
+    # from jax) across the workers so this row benches the same global
+    # device count as the in-process rows it is read against
     env = dict(os.environ)
-    kept = [f for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count")]
+    flags = env.get("XLA_FLAGS", "").split()
+    forced = [f for f in flags
+              if f.startswith("--xla_force_host_platform_device_count=")]
+    pool = int(forced[-1].split("=", 1)[1]) if forced else 1
+    devices = max(1, pool // args.processes)
+    kept = [f for f in flags if f not in forced]
     env["XLA_FLAGS"] = " ".join(
         kept + [f"--xla_force_host_platform_device_count={devices}"]
     )
@@ -608,7 +609,9 @@ def main(argv=None):
                     help=argparse.SUPPRESS)  # internal: worker process id
     ap.add_argument("--port", type=int, default=None, help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.multihost_worker is not None:
         _bench_multihost_worker(args)
         return None
@@ -620,6 +623,11 @@ def main(argv=None):
     from repro.models import LSTMModel
     from repro.optim import sgd
 
+    if args.processes and jax.default_backend() != "cpu":
+        # this process already holds the device; a child that needs it
+        # would fail or hang
+        raise SystemExit("--processes spawns worker processes that each "
+                         "need the device: it runs on the CPU backend only")
     print(f"devices={len(jax.devices())} nodes={args.nodes} rounds={args.rounds} "
           f"chunk={args.chunk} hidden={args.hidden} eval_every={args.eval_every}")
 

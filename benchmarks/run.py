@@ -135,6 +135,9 @@ def main() -> None:
     args = ap.parse_args()
 
     from benchmarks.common import Scale
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     scale = Scale.full() if args.full else Scale()
     if args.quick:

@@ -66,7 +66,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.utils.compat import shard_map as _shard_map
 
 PyTree = Any
 
@@ -361,7 +360,7 @@ def sharded_gossip_mix(
         # output rows), "cols" the trailing one (each shard holds the
         # column block its local params multiply)
         mix_spec = P(*g, None, axes) if mix_block == "cols" else P(*g, axes, None)
-        out = _shard_map(
+        out = jax.shard_map(
             partial(shard_body, axis=axis),
             mesh=mesh,
             in_specs=(P(*g, axes), mix_spec),
@@ -437,7 +436,7 @@ def sharded_gossip_mix_sparse(
         # clamping compares grid-varying indices against replicated
         # bounds, which the replication checker rejects even though the
         # grid axis purely batches here (no collective crosses it)
-        out = _shard_map(
+        out = jax.shard_map(
             partial(sparse_gossip_shard, axis=axis),
             mesh=mesh,
             in_specs=(P(*g, axes), P(*g, axes, None), P(*g, axes, None)),
@@ -516,7 +515,7 @@ def sharded_gossip_mix_gather(
         # check_vma=False for the same reason as the sparse sibling: the
         # in-block index clamp compares grid-varying indices against
         # replicated bounds under the swept engine's spmd vmap
-        out = _shard_map(
+        out = jax.shard_map(
             partial(gather_tables_gossip_shard, axis=axis, n_shards=n_shards),
             mesh=mesh,
             in_specs=(P(*g, axes), P(*g, axes, None), P(*g, axes, None)),
@@ -558,7 +557,7 @@ def make_sharded_gossip(
         def gossip(stacked: PyTree, active: jnp.ndarray) -> PyTree:
             def leaf(l):
                 flat = l.reshape(l.shape[0], -1)
-                out = _shard_map(
+                out = jax.shard_map(
                     partial(ring_gossip_shard, axis=axis, n_shards=n_shards),
                     mesh=mesh,
                     in_specs=(P(node_axes), P(node_axes)),

@@ -1013,6 +1013,11 @@ class GluADFL:
 
         if engine == "loop":
             resolved = self._resolve_eval_fn(eval_fn) if do_eval else None
+            if do_eval and eval_fn is None:
+                # the built-in metric is traceable: compile it, as the scan
+                # engine does — eager op-by-op dispatch and XLA's fused
+                # program differ in the last bits of the RMSE
+                resolved = jax.jit(resolved)
             for t in range(rounds):
                 state, loss = self._round_jit(state, x, y, counts, batch_size=batch_size)
                 rec = {"round": t, "loss": float(loss)}

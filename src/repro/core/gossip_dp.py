@@ -33,7 +33,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.topology import mixing_matrix, round_adjacency
-from repro.utils.compat import shard_map as _shard_map
 
 PyTree = Any
 
@@ -113,7 +112,7 @@ def gossip_mix_params(
 
         # node-replicated leaves: P() on both sides (tensor-parallel
         # sharding goes through ring_mix_params' explicit `specs`)
-        return _shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(), P()),
@@ -158,7 +157,7 @@ def ring_mix_params(params: PyTree, mesh: Mesh, node_axes: tuple[str, ...],
             w_next = jax.lax.ppermute(w_local, axis, bwd)
             return (w_local + w_prev + w_next) / 3.0
 
-        return _shard_map(
+        return jax.shard_map(
             body, mesh=mesh, in_specs=(spec,), out_specs=spec, check_vma=False
         )(w)
 

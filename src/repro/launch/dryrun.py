@@ -268,8 +268,7 @@ def dryrun_one(arch_name: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    from repro.utils.compat import cost_analysis
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     colls = collective_schedule(hlo)
     n_dev = len(mesh.devices.reshape(-1))

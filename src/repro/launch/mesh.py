@@ -7,6 +7,18 @@ the real single device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Every mesh in the repo is built here, with all axes ``Auto``.
+
+    ``jax.make_mesh`` defaults every axis to ``Explicit``; the federation
+    code is written against Auto-axis semantics (``shard_map`` bodies,
+    ``with_sharding_constraint`` and ``vmap(..., spmd_axis_name=)``), so
+    the axis types are stated rather than left to the default."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,15 +26,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     256 (pod x data x model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(devices: int | None = None):
     """Small mesh over whatever devices exist (tests / CPU smoke)."""
     n = devices or len(jax.devices())
     if n >= 4:
-        return jax.make_mesh((n // 2, 2), ("data", "model"))
-    return jax.make_mesh((n, 1), ("data", "model"))
+        return make_mesh((n // 2, 2), ("data", "model"))
+    return make_mesh((n, 1), ("data", "model"))
 
 
 def make_federation_mesh(num_nodes: int, *, devices: int | None = None):
@@ -56,8 +68,8 @@ def make_federation_mesh(num_nodes: int, *, devices: int | None = None):
             picked = [
                 d for p in sorted(by_proc) for d in by_proc[p][: width // procs]
             ]
-            return jax.make_mesh((width,), ("node",), devices=picked)
-    return jax.make_mesh((max(divisors),), ("node",))
+            return make_mesh((width,), ("node",), devices=picked)
+    return make_mesh((max(divisors),), ("node",))
 
 
 def _sweep_mesh_widths(num_scenarios: int, num_nodes: int, avail: int) -> tuple[int, int]:
@@ -106,7 +118,7 @@ def make_sweep_mesh(
             f"G={num_scenarios} % grid_width={grid_width} and "
             f"N={num_nodes} % node_width={node_width} must both be 0"
         )
-    return jax.make_mesh((grid_width, node_width), ("grid", "node"))
+    return make_mesh((grid_width, node_width), ("grid", "node"))
 
 
 # the auto-knob policies (choose_gossip_impl / choose_gossip_repr and
@@ -128,7 +140,7 @@ def make_gossip_dp_mesh(*, nodes: int = 4, multi_pod: bool = False):
     if multi_pod:
         # nodes = 2 pods x (nodes // 2) groups
         per_pod = max(nodes // 2, 1)
-        return jax.make_mesh(
+        return make_mesh(
             (2, per_pod, 16 // per_pod, 16), ("pod", "node", "data", "model")
         )
-    return jax.make_mesh((nodes, 16 // nodes, 16), ("node", "data", "model"))
+    return make_mesh((nodes, 16 // nodes, 16), ("node", "data", "model"))

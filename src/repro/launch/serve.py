@@ -43,6 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.data import load_federated_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import GlucoseServable, MicroBatcher, Request, load_population
 
 DEFAULT_CKPT = "experiments/checkpoints/gluadfl_ohiot1dm_ring.npz"
@@ -108,6 +109,7 @@ def main(argv=None) -> int:
                     help="assert every served forecast bitwise-matches "
                          "direct model.apply; exit 1 on mismatch")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     buckets = tuple(int(b) for b in args.buckets.split(",") if b)
     model, pop = load_population(args.checkpoint, hidden=args.hidden)

@@ -119,6 +119,7 @@ import numpy as np
 from repro.config import ExperimentConfig, apply_overrides
 from repro.core import GluADFL
 from repro.data import load_federated_dataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.metrics import all_metrics
 from repro.models import LSTMModel
 from repro.optim import get_optimizer
@@ -231,6 +232,7 @@ def main():
     ap.add_argument("--out", default="experiments/checkpoints")
     ap.add_argument("overrides", nargs="*", help="cfg overrides a.b=c")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.use_kernel:
         import warnings

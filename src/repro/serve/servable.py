@@ -26,7 +26,9 @@ program by default (``batch_mode="map"``): XLA lowers a ``vmap``-batched
 LSTM differently (batched matmuls, ~1e-8 drift vs a B=1 apply), and the
 serving contract here is bit-reproducibility — a forecast must not
 depend on who else happened to share the batch.  ``batch_mode="vmap"``
-trades that guarantee for row-parallel throughput.
+trades that guarantee for row-parallel throughput.  The contract holds
+on the CPU and on a TPU v5e alike (``chip_smoke.py`` checks it there at
+hidden 128, ``launch/serve.py --selfcheck`` at the checkpoint's width).
 
 The batching POLICY (queueing, admission, timeouts) lives in
 ``serve.batcher``; :func:`replay` is the deterministic driver that

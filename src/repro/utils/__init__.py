@@ -1,6 +1,5 @@
 """Small shared utilities: pytree algebra (stack/index/mean/mix — the
-federation's stacked-leaf operations), RNG helpers, and version-compat
-shims (``utils.compat.shard_map``)."""
+federation's stacked-leaf operations) and RNG helpers."""
 from repro.utils.pytree import (
     tree_vector_size,
     tree_to_vector,

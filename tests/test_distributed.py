@@ -34,7 +34,8 @@ def test_sharded_ring_gossip_matches_reference():
         from repro.core.distributed import make_sharded_gossip
         from repro.core.topology import mixing_matrix, ring_adjacency
         from repro.utils.pytree import tree_weighted_mix
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         N, D = 8, 96
         w = {"a": jax.random.normal(jax.random.PRNGKey(0), (N, D)),
              "b": jax.random.normal(jax.random.PRNGKey(1), (N, 3, 5))}
@@ -54,7 +55,8 @@ def test_sharded_general_gossip_matches_reference():
         from repro.core.distributed import make_sharded_gossip
         from repro.core.topology import mixing_matrix, cluster_adjacency
         from repro.utils.pytree import tree_weighted_mix
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         N, D = 8, 64
         w = {"a": jax.random.normal(jax.random.PRNGKey(0), (N, D))}
         active = (jax.random.uniform(jax.random.PRNGKey(2), (N,)) > 0.4).astype(jnp.float32)
@@ -78,7 +80,8 @@ def test_ring_gossip_multi_node_shards_match_reference():
         from repro.core.distributed import make_sharded_gossip
         from repro.core.topology import mixing_matrix, ring_adjacency
         from repro.utils.pytree import tree_weighted_mix
-        mesh = jax.make_mesh((4,), ("data",))  # 8 nodes -> 2 per shard
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))  # 8 nodes -> 2 per shard
         N, D = 8, 24
         w = {"a": jax.random.normal(jax.random.PRNGKey(0), (N, D)),
              "b": jax.random.normal(jax.random.PRNGKey(1), (N, 3, 5))}
@@ -170,7 +173,8 @@ def test_sharded_ring_gossip_respects_inactive():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.distributed import make_sharded_gossip
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         N, D = 8, 16
         w = {"a": jax.random.normal(jax.random.PRNGKey(0), (N, D))}
         active = jnp.zeros((N,)).at[0].set(1.0)
@@ -259,7 +263,8 @@ def test_mini_dryrun_dense_and_moe():
         from repro.arch.common import init_train_state, make_train_step
         from repro.arch.sharding import param_pspecs
         from repro.config import get_arch_config
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         for name in ("yi-6b", "granite-moe-1b-a400m", "mamba2-370m"):
             cfg = get_arch_config(name).reduced()
             arch = build_arch(cfg)
@@ -277,8 +282,7 @@ def test_mini_dryrun_dense_and_moe():
             with mesh:
                 fn = jax.jit(step, in_shardings=(st_sh, bsh))
                 compiled = fn.lower(st_spec, batch).compile()
-            from repro.utils.compat import cost_analysis
-            cost = cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             assert cost.get("flops", 0) > 0, name
             print("MINI_DRYRUN_OK", name, int(cost["flops"]))
     """))
@@ -374,7 +378,8 @@ def test_gossip_dp_psum_scatter_matches_full_psum():
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.gossip_dp import gossip_mix_params
         from repro.core.topology import mixing_matrix, random_adjacency
-        mesh = jax.make_mesh((4, 2), ("node", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("node", "model"))
         k = jax.random.split(jax.random.PRNGKey(0), 2)
         params = {"w": jax.random.normal(k[0], (4, 8, 6)), "b": jnp.zeros((3,))}
         mix = mixing_matrix(random_adjacency(jax.random.PRNGKey(3), 4, 2),
@@ -404,9 +409,50 @@ def test_gossip_dp_ring_mix_on_mesh():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.gossip_dp import ring_mix_params
-        mesh = jax.make_mesh((4, 2), ("node", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("node", "model"))
         params = {"w": jnp.ones((8, 8)), "b": jnp.zeros((3,))}
         out = jax.jit(lambda p: ring_mix_params(p, mesh, ("node",)))(params)
         np.testing.assert_allclose(np.asarray(out["w"]), 1.0, atol=1e-6)
         print("GOSSIP_DP_OK")
     """))
+
+
+@pytest.mark.multidevice
+def test_every_mesh_has_auto_axes():
+    """Every mesh ``launch/mesh.py`` builds has Auto axes (the installed
+    jax.make_mesh defaults to Explicit, which the shard_map bodies,
+    sharding constraints and spmd vmaps are not written for).  4 forced
+    devices for the federation meshes; the pod-scale meshes need 512."""
+    print(_run("""
+        from jax.sharding import AxisType
+        from repro.launch import mesh as M
+        meshes = [M.make_mesh((2, 2), ("a", "b")), M.make_test_mesh(),
+                  M.make_federation_mesh(224), M.make_federation_mesh(226),
+                  M.make_sweep_mesh(2, 226)]
+        for m in meshes:
+            assert all(t == AxisType.Auto for t in m.axis_types), m
+        print("AUTO_4_OK")
+    """, devices=4))
+    print(_run("""
+        from jax.sharding import AxisType
+        from repro.launch import mesh as M
+        for m in (M.make_production_mesh(), M.make_production_mesh(multi_pod=True),
+                  M.make_gossip_dp_mesh(), M.make_gossip_dp_mesh(multi_pod=True)):
+            assert all(t == AxisType.Auto for t in m.axis_types), m
+        print("AUTO_512_OK")
+    """, devices=512))
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("nodes,width", [(224, 4), (226, 2)])
+def test_federation_mesh_width_on_four_devices(nodes, width):
+    """The node axis takes the largest divisor of N that fits the
+    devices: 224 = 4·56 spans all four, while the paper's 226 = 2·113
+    cohort shards only two-wide on a four-chip host."""
+    out = _run(f"""
+        from repro.launch.mesh import make_federation_mesh
+        m = make_federation_mesh({nodes})
+        print("WIDTH", m.shape["node"], len({{d.id for d in m.devices.flat}}))
+    """, devices=4)
+    assert f"WIDTH {width} {width}" in out, out

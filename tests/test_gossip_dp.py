@@ -23,6 +23,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.core.gossip_dp import GossipDPSchedule, ring_mix_params
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -73,7 +74,7 @@ def test_schedule_cadence():
 def test_ring_mix_specs_leaf_mismatch_raises():
     """A specs tree with the wrong leaf count must refuse loudly — the
     old ``zip`` silently truncated and mixed the tail as replicated."""
-    mesh = jax.make_mesh((1, 1), ("node", "model"))
+    mesh = make_mesh((1, 1), ("node", "model"))
     params = {"a": np.ones((4,)), "b": np.ones((4,))}
     with pytest.raises(ValueError, match="leaves"):
         ring_mix_params(params, mesh, ("node",), specs={"a": P(None)})
@@ -86,19 +87,19 @@ def test_ring_mix_specs_leaf_mismatch_raises():
 _SCATTER_GATHER = """
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.utils.compat import shard_map as _shard_map
+    from repro.launch.mesh import make_mesh
 
     def node_varying(mesh):
         # params in gossip-DP are replicated over the node axes (P()),
         # so per-node divergence must be built INSIDE the program:
         # scatter hands node i row i of a host (N, D) base, gather
         # reads the per-node values back out as (N, D)
-        scatter = _shard_map(lambda b: b[jax.lax.axis_index('node')],
-                             mesh=mesh, in_specs=(P(),), out_specs=P(),
-                             check_vma=False)
-        gather = _shard_map(lambda w: jax.lax.all_gather(w, 'node'),
-                            mesh=mesh, in_specs=(P(),), out_specs=P(),
-                            check_vma=False)
+        scatter = jax.shard_map(lambda b: b[jax.lax.axis_index('node')],
+                                mesh=mesh, in_specs=(P(),), out_specs=P(),
+                                check_vma=False)
+        gather = jax.shard_map(lambda w: jax.lax.all_gather(w, 'node'),
+                               mesh=mesh, in_specs=(P(),), out_specs=P(),
+                               check_vma=False)
         return scatter, gather
 """
 
@@ -111,7 +112,7 @@ def test_gossip_mix_params_matches_dense_oracle():
     from repro.core.topology import mixing_matrix, random_adjacency
 
     N, D = 4, 96
-    mesh = jax.make_mesh((N, 2), ('node', 'model'))
+    mesh = make_mesh((N, 2), ('node', 'model'))
     base = jax.random.normal(jax.random.PRNGKey(0), (N, D))
     adj = random_adjacency(jax.random.PRNGKey(3), N, 2)
     active = jnp.array([1.0, 0.0, 1.0, 1.0])
@@ -144,7 +145,7 @@ def test_ring_mix_matches_mixing_matrix_oracle():
 
     D = 64
     for N in (2, 4, 8):
-        mesh = jax.make_mesh((N, 8 // N), ('node', 'model'))
+        mesh = make_mesh((N, 8 // N), ('node', 'model'))
         base = jax.random.normal(jax.random.PRNGKey(N), (N, D))
         scatter, gather = node_varying(mesh)
 

@@ -30,6 +30,7 @@ from repro.core.gossip_plan import (
 from repro.core.topology import neighbor_table, random_adjacency
 from repro.models import LSTMModel
 from repro.optim import sgd
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -211,11 +212,11 @@ def test_choose_gossip_repr_mesh_aware():
     assert choose_gossip_repr(32, 7) == "sparse"
     # mesh path: same (N, B) flips to sparse once the per-device
     # (N/shards, N) row block outgrows the budget
-    mesh = jax.make_mesh((1,), ("node",))
+    mesh = make_mesh((1,), ("node",))
     assert choose_gossip_repr(31, 7, mesh=mesh) == "dense"
     assert choose_gossip_repr(31, 7, mesh=mesh, budget_bytes=31 * 31) == "sparse"
     # grid/model axes don't count toward the node width
-    gm = jax.make_mesh((1, 1), ("grid", "node"))
+    gm = make_mesh((1, 1), ("grid", "node"))
     assert choose_gossip_repr(31, 7, mesh=gm, budget_bytes=31 * 31) == "sparse"
 
 

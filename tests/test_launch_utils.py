@@ -7,6 +7,7 @@ import pytest
 
 from repro.config import ExperimentConfig, apply_overrides, get_arch_config
 from repro.launch.dryrun import _shape_bytes, collective_schedule
+from repro.launch.mesh import make_mesh
 
 
 SAMPLE_HLO = """
@@ -99,7 +100,7 @@ def test_gossip_dp_ring_specs_roundtrip():
 
     from repro.core.gossip_dp import ring_mix_params
 
-    mesh = jax.make_mesh((1,), ("node",))
+    mesh = make_mesh((1,), ("node",))
     params = {"w": jnp.arange(12.0).reshape(3, 4)}
     specs = {"w": P(None, None)}
     out = ring_mix_params(params, mesh, ("node",), specs=specs)

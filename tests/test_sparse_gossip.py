@@ -253,7 +253,8 @@ def test_sharded_sparse_grid_stacked():
         from repro.core.topology import (mixing_matrix_stacked, random_adjacency,
                                          stacked_neighbor_table)
         G, N, D = 4, 8, 64
-        mesh = jax.make_mesh((2, 4), ("grid", "node"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("grid", "node"))
         adjs = jnp.stack([random_adjacency(jax.random.PRNGKey(i), N, 3) for i in range(G)])
         acts = (jax.random.uniform(jax.random.PRNGKey(9), (G, N)) > 0.3).astype(jnp.float32)
         si, sw = stacked_neighbor_table(adjs, acts, 3)

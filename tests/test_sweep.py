@@ -27,6 +27,7 @@ from repro.core.topology import (
 from repro.models import LSTMModel
 from repro.optim import adam, sgd
 from repro.utils.pytree import tree_index, tree_l2_norm, tree_sub
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -264,7 +265,7 @@ def test_train_sweep_guards():
     # the swept-sharded engine needs the 2-D (grid, node) mesh — a 1-D
     # federation mesh is the serial train() layout, not the sweep's
     tr_1d = GluADFL(model, sgd(1e-2), FLConfig(num_nodes=6),
-                    mixer="sharded", mesh=jax.make_mesh((1,), ("node",)))
+                    mixer="sharded", mesh=make_mesh((1,), ("node",)))
     with pytest.raises(ValueError, match="2-D"):
         tr_1d.train_sweep(*_toy_fed(), grid=grid6)
     with pytest.raises(ValueError, match="empty"):
