@@ -154,6 +154,17 @@ PyTree = Any
 # the host-side history granularity stay reasonable
 DEFAULT_CHUNK = 32
 
+# the stages of one round, each a ``jax.named_scope("round.<stage>")``
+# around the ops it emits: the names reach the compiled ops' ``op_name``
+# metadata only (the program is otherwise the same op for op), from
+# which ``bench/trace_stages.py`` attributes device time to a stage.
+# Scopes never nest.
+ROUND_STAGES = ("neighbours", "gossip", "local_step", "adam", "select")
+
+
+def _stage(name: str):
+    return jax.named_scope(f"round.{name}")
+
 
 def _to_host(v):
     """numpy copy of a per-chunk device output (losses, eval records).
@@ -535,21 +546,26 @@ class GluADFL:
 
         def one_step(carry, k):
             p_for_grad, p_apply, st = carry
-            bx, by = self._sample_batch(k, x, y, count, batch_size)
-            if shift is not None:
-                bx = bx + shift
-                by = by + shift
-            loss, grads = jax.value_and_grad(self.loss_fn)(p_for_grad, bx, by)
-            new_p, new_st = self.optimizer.update(grads, st, p_apply)
+            with _stage("local_step"):
+                bx, by = self._sample_batch(k, x, y, count, batch_size)
+                if shift is not None:
+                    bx = bx + shift
+                    by = by + shift
+                loss, grads = jax.value_and_grad(self.loss_fn)(p_for_grad, bx, by)
+            with _stage("adam"):
+                new_p, new_st = self.optimizer.update(grads, st, p_apply)
             # subsequent local steps are ordinary SGD at the new params
             return (new_p, new_p, new_st), loss
 
-        keys = jax.random.split(key, self.cfg.local_steps)
+        with _stage("local_step"):
+            keys = jax.random.split(key, self.cfg.local_steps)
         first_grad_p = params_premix if self.grad_at == "premix" else params_mixed
         (p, _, st), losses = jax.lax.scan(
             one_step, (first_grad_p, params_mixed, opt_state), keys
         )
-        return p, st, jnp.mean(losses)
+        with _stage("local_step"):
+            loss = jnp.mean(losses)
+        return p, st, loss
 
     # ------------------------------------------------------------------
     def _mix_repr(self, adj: jnp.ndarray, active) -> Any:
@@ -697,108 +713,110 @@ class GluADFL:
         the default federation mesh."""
         cfg = self.cfg
         n = cfg.num_nodes
-        key, k_act, k_top, k_batch = jax.random.split(state.key, 4)
-
         sc_markov = sc_skew = sc_dp = None
         if scenario is not None and len(scenario) == 6:
             adj_static, resample, inactive_ratio, sc_markov, sc_skew, sc_dp = scenario
         elif scenario is not None:
             adj_static, resample, inactive_ratio = scenario
 
-        # a node that ended last round with staleness 0 participated in
-        # it — the markov chain's previous state, derivable in the swept
-        # and serial paths alike (staleness is carried in FLState)
-        prev_active = (state.staleness == 0).astype(jnp.float32)
-        adj = None  # stays None on the sparse static-topology fast path
-        if scenario is None:
-            if cfg.schedule == "markov":
-                active = markov_active(
-                    k_act, prev_active, cfg.p_stay_active, cfg.p_stay_inactive
-                )
+        with _stage("neighbours"):
+            key, k_act, k_top, k_batch = jax.random.split(state.key, 4)
+            # a node that ended last round with staleness 0 participated in
+            # it — the markov chain's previous state, derivable in the swept
+            # and serial paths alike (staleness is carried in FLState)
+            prev_active = (state.staleness == 0).astype(jnp.float32)
+            adj = None  # stays None on the sparse static-topology fast path
+            if scenario is None:
+                if cfg.schedule == "markov":
+                    active = markov_active(
+                        k_act, prev_active, cfg.p_stay_active, cfg.p_stay_inactive
+                    )
+                else:
+                    active = bernoulli_active(k_act, n, cfg.inactive_ratio)
+                if self._neighbor_cand is not None:
+                    # sparse static topology: table straight from the host-
+                    # built candidate lists — no (N, N) array in the program
+                    cand_idx, cand_valid = self._neighbor_cand
+                    mix = neighbor_table_from_candidates(
+                        cand_idx, cand_valid, active, cfg.comm_batch
+                    )
+                else:
+                    adj = round_adjacency(
+                        cfg.topology, n, k_top, cfg.comm_batch, cfg.cluster_size
+                    )
+                    mix = self._mix_repr(adj, active)
             else:
-                active = bernoulli_active(k_act, n, cfg.inactive_ratio)
-            if self._neighbor_cand is not None:
-                # sparse static topology: table straight from the host-
-                # built candidate lists — no (N, N) array in the program
-                cand_idx, cand_valid = self._neighbor_cand
-                mix = neighbor_table_from_candidates(
-                    cand_idx, cand_valid, active, cfg.comm_batch
-                )
-            else:
-                adj = round_adjacency(
-                    cfg.topology, n, k_top, cfg.comm_batch, cfg.cluster_size
+                if sc_markov is None:
+                    active = bernoulli_active(k_act, n, inactive_ratio)
+                else:
+                    # per-scenario schedule choice as a select: both masks
+                    # read the SAME uniform(k_act, (N,)) draw, so arming the
+                    # axis never shifts the main key chain
+                    active = jnp.where(
+                        sc_markov > 0,
+                        markov_active(
+                            k_act, prev_active, cfg.p_stay_active, cfg.p_stay_inactive
+                        ),
+                        bernoulli_active(k_act, n, inactive_ratio),
+                    )
+                # both graph flavours are cheap relative to the local step,
+                # so the data-dependent choice is a select, not a cond:
+                # random topologies draw from the SAME k_top a serial run
+                # would use
+                adj = jnp.where(
+                    resample > 0,
+                    random_adjacency(k_top, n, min(cfg.comm_batch, n - 1)),
+                    adj_static,
                 )
                 mix = self._mix_repr(adj, active)
-        else:
-            if sc_markov is None:
-                active = bernoulli_active(k_act, n, inactive_ratio)
-            else:
-                # per-scenario schedule choice as a select: both masks
-                # read the SAME uniform(k_act, (N,)) draw, so arming the
-                # axis never shifts the main key chain
-                active = jnp.where(
-                    sc_markov > 0,
-                    markov_active(
-                        k_act, prev_active, cfg.p_stay_active, cfg.p_stay_inactive
-                    ),
-                    bernoulli_active(k_act, n, inactive_ratio),
-                )
-            # both graph flavours are cheap relative to the local step, so
-            # the data-dependent choice is a select, not a cond: random
-            # topologies draw from the SAME k_top a serial run would use
-            adj = jnp.where(
-                resample > 0,
-                random_adjacency(k_top, n, min(cfg.comm_batch, n - 1)),
-                adj_static,
-            )
-            mix = self._mix_repr(adj, active)
+            k_dp = None
+            if sc_dp is not None or self.dp_noise_sigma > 0.0:
+                key, k_dp = jax.random.split(key)
 
         premix = state.params
-        k_dp = None
-        if sc_dp is not None or self.dp_noise_sigma > 0.0:
-            key, k_dp = jax.random.split(key)
-        mask_ctx = None
-        if self.plan.masked:
-            # the mask stream is FOLDED off the round key, never split:
-            # enabling secure aggregation must not perturb the
-            # activity/topology/batch/DP key chain (the bitwise-parity
-            # contract)
-            k_mask = jax.random.fold_in(state.key, MASK_STREAM_TAG)
-            mask_ctx = (k_mask, self.plan.mask_table(mix, adj, active))
-        mixed = self._gossip(premix, mix, active, k_dp, mesh, mask_ctx, sc_dp)
+        with _stage("gossip"):
+            mask_ctx = None
+            if self.plan.masked:
+                # the mask stream is FOLDED off the round key, never split:
+                # enabling secure aggregation must not perturb the
+                # activity/topology/batch/DP key chain (the bitwise-parity
+                # contract)
+                k_mask = jax.random.fold_in(state.key, MASK_STREAM_TAG)
+                mask_ctx = (k_mask, self.plan.mask_table(mix, adj, active))
+            mixed = self._gossip(premix, mix, active, k_dp, mesh, mask_ctx, sc_dp)
 
-        node_keys = jax.random.split(k_batch, n)
+        with _stage("local_step"):
+            node_keys = jax.random.split(k_batch, n)
+            args = (node_keys, premix, mixed, state.opt_state, x, y, counts)
+            if sc_skew is not None or cfg.data_skew != 0.0:
+                # per-node batch shift: the offsets are a trace-time
+                # constant table, scaled by the (possibly traced) scenario
+                # skew
+                skew = cfg.data_skew if sc_skew is None else sc_skew
+                args += (skew * jnp.asarray(node_skew_offsets(n)),)
+        # the local step's scopes sit inside it, under the vmap and scan
         step = partial(self._local_step, batch_size=batch_size)
-        if sc_skew is None and cfg.data_skew == 0.0:
-            new_params, new_opt, losses = jax.vmap(step)(
-                node_keys, premix, mixed, state.opt_state, x, y, counts
-            )
-        else:
-            # per-node batch shift: the offsets are a trace-time constant
-            # table, scaled by the (possibly traced) scenario skew
-            skew = cfg.data_skew if sc_skew is None else sc_skew
-            shift = skew * jnp.asarray(node_skew_offsets(n))
-            new_params, new_opt, losses = jax.vmap(step)(
-                node_keys, premix, mixed, state.opt_state, x, y, counts, shift
-            )
+        new_params, new_opt, losses = jax.vmap(step)(*args)
 
-        # inactive nodes keep their stale params / optimizer state.
-        # jnp.where (not arithmetic blending) so inactive rows are BITWISE
-        # copies and integer leaves (optimizer step) keep their dtype —
-        # the scan carry must be type-stable across rounds.
-        def mask(new, old):
-            bshape = (n,) + (1,) * (new.ndim - 1)
-            a = active.reshape(bshape) > 0
-            return jnp.where(a, new, old)
+        with _stage("select"):
+            # inactive nodes keep their stale params / optimizer state.
+            # jnp.where (not arithmetic blending) so inactive rows are
+            # BITWISE copies and integer leaves (optimizer step) keep their
+            # dtype — the scan carry must be type-stable across rounds.
+            def mask(new, old):
+                bshape = (n,) + (1,) * (new.ndim - 1)
+                a = active.reshape(bshape) > 0
+                return jnp.where(a, new, old)
 
-        params = jax.tree.map(mask, new_params, premix)
-        opt_state = jax.tree.map(
-            lambda nw, od: mask(nw, od) if nw.ndim >= 1 and nw.shape[:1] == (n,) else nw,
-            new_opt,
-            state.opt_state,
-        )
-        loss = jnp.sum(losses * active) / jnp.maximum(jnp.sum(active), 1.0)
-        new_round = state.round + 1
+            params = jax.tree.map(mask, new_params, premix)
+            opt_state = jax.tree.map(
+                lambda nw, od: mask(nw, od) if nw.ndim >= 1 and nw.shape[:1] == (n,) else nw,
+                new_opt,
+                state.opt_state,
+            )
+            loss = jnp.sum(losses * active) / jnp.maximum(jnp.sum(active), 1.0)
+            new_round = state.round + 1
+            staleness = staleness_update(state.staleness, active)
         aux = loss
         if eval_every and eval_fn is not None:
             metrics = self._eval_metrics(
@@ -809,7 +827,7 @@ class GluADFL:
             FLState(
                 params=params,
                 opt_state=opt_state,
-                staleness=staleness_update(state.staleness, active),
+                staleness=staleness,
                 round=new_round,
                 key=key,
             ),
