@@ -74,13 +74,15 @@ def test_scopes_never_nest(instrs):
 
 
 def test_lstm_forward_and_transposed_dots_are_the_local_step(instrs):
-    """The LSTM's matmuls, forward (``jvp``) and backward
-    (``transpose(jvp())``), all sit under ``round.local_step``."""
+    """The LSTM's matmuls, forward (``jvp``) and backward (under a
+    ``transpose(...)``: ``transpose(jvp())`` for autodiff, and
+    ``transpose(round.local_step)`` for the LSTM's custom VJP, whose
+    name stack repeats the scope), all sit under ``round.local_step``."""
     dots = [op for _, code, op in instrs if code in ("dot", "convolution")]
     lstm = [op for op in dots if "jvp(" in op]
-    assert any("transpose(jvp" in op for op in lstm)
-    assert any("jvp(" in op and "transpose(" not in op for op in lstm)
-    assert all(stages_of(op) == ["local_step"] for op in lstm)
+    assert any("transpose(" in op for op in lstm)
+    assert any("transpose(" not in op for op in lstm)
+    assert all(set(stages_of(op)) == {"local_step"} for op in lstm)
 
 
 def test_adam_update_is_the_adam_stage(instrs):
