@@ -45,7 +45,8 @@ multi-round engine a compiler problem rather than a host loop:
     side effects, pdb between rounds).  Same numerics, one dispatch per
     round; it is never selected automatically.
   * **Mixer modes** — the gossip contraction dispatches on ``mixer``:
-      - ``"tree"``     reference einsum per leaf (CPU default),
+      - ``"tree"``     einsum per leaf at ``HIGHEST`` precision (f32-exact
+        on the TPU too), the default,
       - ``"kernel"``   Pallas VMEM-blocked kernel (interpret on CPU); the
         local-DP path fuses noise-broadcast + mix + clean-self-restore
         into the kernel's single pass over the (N, D) matrix
@@ -59,12 +60,16 @@ multi-round engine a compiler problem rather than a host loop:
     contracts the (N, N) ``topology.mixing_matrix``; ``"sparse"`` uses
     the (N, B+1) neighbor table (``topology.neighbor_table``) whose
     densification is bitwise the same matrix, cutting the contraction
-    from O(N²·D) to O(N·B·D).  Static topologies build the table from
-    host-side candidate lists so no (N, N) array exists anywhere —
-    federations of 10k+ nodes train where the dense path OOMs
-    (``sparse-gossip-10k`` bench row).  ``"auto"`` defers to
-    ``launch.mesh.choose_gossip_repr`` (sparse once B+1 ≪ N).  Every
-    mixer has a sparse twin, including the fused DP kernel.
+    from O(N²·D) FLOPs to O(N·B·D), at the price of gathering B+1
+    copies of every node's parameters.  Static topologies build the
+    table from host-side candidate lists so no (N, N) array exists
+    anywhere — federations of 10k+ nodes train where the dense path
+    OOMs (``sparse-gossip-10k`` bench row).  ``"auto"`` defers to
+    ``launch.mesh.choose_gossip_repr``: the tree mixer on one device
+    stays dense below its dense/sparse crossover (dense at the paper's
+    N=226); across devices, and for the other mixers, sparse once
+    N >= 4·(B+1).  Every mixer has a sparse twin,
+    including the fused DP kernel.
 
 All RNG is threaded through ``FLState.key`` so every engine/mixer
 combination consumes the identical key stream: ``train_chunk(chunk=k)``

@@ -5,7 +5,9 @@ row-stochastic mixing matrix ``M`` (from ``topology.mixing_matrix``),
 compute ``W <- M @ W``.
 
 Three interchangeable implementations:
-  * ``gossip_mix_tree``    — pure jnp einsum per leaf (reference; CPU),
+  * ``gossip_mix_tree``    — jnp einsum per leaf at ``HIGHEST`` precision
+                             (f32-exact on the TPU; the one-device
+                             default below the dense/sparse crossover),
   * ``gossip_mix_kernel``  — Pallas blocked kernel (repro.kernels),
   * ``sharded_gossip_mix`` — shard_map over a node-sharded axis
                              (repro.core.distributed) for fleet scale.
@@ -13,7 +15,8 @@ Three interchangeable implementations:
 Each has a ``*_sparse`` twin taking ``core.topology.neighbor_table``'s
 (N, B+1) ``(idx, wgt)`` representation instead of the dense (N, N)
 matrix — same math to float tolerance (bitwise for inactive rows, which
-take a where-select copy), O(N·B·D) instead of O(N²·D).
+take a where-select copy), O(N·B·D) FLOPs instead of O(N²·D), but B+1
+gathered copies of the parameters where the dense form reads them once.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ PyTree = Any
 
 
 def gossip_mix_tree(stacked_params: PyTree, mix: jnp.ndarray) -> PyTree:
-    """Reference implementation (einsum per leaf)."""
+    """Dense implementation: one f32-exact einsum per leaf."""
     return tree_weighted_mix(stacked_params, mix)
 
 

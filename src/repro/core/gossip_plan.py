@@ -339,8 +339,17 @@ def choose_gossip_impl(
 
 
 # sparse tables win once the kept row (B+1 entries) is a small fraction
-# of N; 4x covers the gather/top_k bookkeeping the dense matmul doesn't pay
+# of N.  Across a node axis wider than one device this is the FLOP
+# heuristic: 4x covers the gather/top_k bookkeeping the dense matmul
+# doesn't pay (never timed on chips).
 SPARSE_GOSSIP_FACTOR = 4
+# The tree mixer with the node axis on one device: the dense contraction
+# reads and writes each node's parameters once, while the table's gather
+# copies them B+1 times, so dense wins past the FLOP break-even.  Timed
+# on one TPU v5e (build + mix a round, B = 7, D = 66,689, random graph):
+# dense was 2.3x to 4.5x faster at every N from 226 to 4096, the largest
+# timed (PERF.md, PR 17), so the boundary sits there: N = 4096 at B = 7.
+ONE_DEVICE_SPARSE_GOSSIP_FACTOR = 512
 
 
 def _node_axis_width(mesh) -> int:
@@ -358,32 +367,40 @@ def choose_gossip_repr(
     num_nodes: int,
     comm_batch: int,
     *,
-    factor: int = SPARSE_GOSSIP_FACTOR,
+    factor: int | None = None,
     mesh=None,
     budget_bytes: int = DEFAULT_GATHER_BUDGET_BYTES,
 ) -> str:
     """Mixing-operator representation selection (``--gossip-repr auto``).
 
     Every mixing row has at most ``comm_batch + 1`` nonzeros (Algorithm 1
-    caps each node at B neighbours), so the dense (N, N) matrix carries
-    ``N / (B+1)``-fold pure waste.  Pick the sparse neighbor table
-    (``core.topology.neighbor_table``) once ``B+1 ≪ N`` — concretely
-    ``num_nodes >= factor * (comm_batch + 1)`` — and keep the dense
-    matrix for small federations where the one-matmul contraction is
-    simpler than the gather and the waste is noise.  At the paper's
-    N=226 / B=7 this picks sparse (226 >= 32); a 16-node smoke test
-    stays dense.
+    caps each node at B neighbours), so the sparse neighbor table
+    (``core.topology.neighbor_table``) does ``N / (B+1)``-fold fewer
+    FLOPs than the dense (N, N) matrix — but it gathers B+1 copies of
+    every node's parameters, where the dense contraction reads them
+    once.  Pick sparse once ``num_nodes >= factor * (comm_batch + 1)``.
+    An explicit ``factor`` is used as given (``resolve_gossip_plan``
+    passes ``SPARSE_GOSSIP_FACTOR`` for every backend but the tree
+    mixer); ``None`` picks it from where the node axis sits:
+
+    * on one device (no ``mesh``, or a node-axis width of 1):
+      ``ONE_DEVICE_SPARSE_GOSSIP_FACTOR``, the tree backend's
+      dense/sparse crossover on one chip.  At the paper's N=226 / B=7
+      this picks dense;
+    * across devices: ``SPARSE_GOSSIP_FACTOR`` (4), so N >= 32 at B = 7
+      goes sparse.
 
     Mesh-aware (the sharded mixer's path): with a ``mesh``, the dense
     representation additionally keeps an ``(N/shards, N)`` row block of
     the mixing matrix resident on every device — once that block alone
-    outgrows ``budget_bytes`` the flop heuristic is moot and only the
-    ``(N/shards, B+1)`` table fits, so sparse is forced regardless of
-    ``factor``.  Without a mesh the choice depends on (N, B) only."""
+    outgrows ``budget_bytes`` only the ``(N/shards, B+1)`` table fits,
+    so sparse is forced regardless of the factor."""
+    shards = 1 if mesh is None else _node_axis_width(mesh)
+    if factor is None:
+        factor = ONE_DEVICE_SPARSE_GOSSIP_FACTOR if shards == 1 else SPARSE_GOSSIP_FACTOR
     if num_nodes >= factor * (comm_batch + 1):
         return "sparse"
     if mesh is not None:
-        shards = _node_axis_width(mesh)
         per_device_matrix = (num_nodes // shards) * num_nodes * 4  # f32
         if per_device_matrix > budget_bytes:
             return "sparse"
@@ -595,8 +612,13 @@ def resolve_gossip_plan(
         raise ValueError(f"mixer {mixer!r} not in {MIXERS}")
     if gossip_impl not in GOSSIP_IMPLS:
         raise ValueError(f"gossip_impl {gossip_impl!r} not in {GOSSIP_IMPLS}")
+    backend = _backend_for(mixer, gossip_impl)
     if gossip_repr == "auto":
-        gossip_repr = choose_gossip_repr(num_nodes, comm_batch, mesh=mesh)
+        # the one-device crossover is the tree backend's, whose sparse
+        # form copies B+1 rows per node; the other backends keep the
+        # FLOP rule
+        factor = None if backend.name == "tree" else SPARSE_GOSSIP_FACTOR
+        gossip_repr = choose_gossip_repr(num_nodes, comm_batch, factor=factor, mesh=mesh)
     if gossip_repr not in GOSSIP_REPRS:
         raise ValueError(
             f"gossip_repr {gossip_repr!r} not in {GOSSIP_REPRS}; 'auto' "
@@ -604,7 +626,6 @@ def resolve_gossip_plan(
             f"this check"
         )
 
-    backend = _backend_for(mixer, gossip_impl)
     if gossip_repr == "sparse" and not backend.caps.supports_sparse:
         raise GossipPlanError(
             f"backend {backend.name!r} does not support gossip_repr='sparse'"

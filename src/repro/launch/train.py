@@ -73,9 +73,12 @@ Gossip representation (any mixer)
 ``--gossip-repr dense|sparse|auto`` picks the mixing operator's storage:
 the dense (N, N) ``mixing_matrix`` or the (N, B+1) neighbor table
 (O(N·B·D) contraction, no (N, N) array for static topologies — the
-population-scale path).  ``auto`` (default) goes sparse once
-``B+1 ≪ N`` (``launch.mesh.choose_gossip_repr``): sparse at the paper's
-N=226, dense on small smoke runs.
+population-scale path).  ``auto`` (default) defers to
+``launch.mesh.choose_gossip_repr``: the tree mixer with the node axis
+on one device stays dense below its dense/sparse crossover (dense at
+the paper's N=226, which reads each node's parameters once where the
+table copies them B+1 times); across devices, and for the other
+mixers, sparse once N >= 4·(B+1).
 
 Multi-host bootstrap (``--num-processes > 1``)
 ----------------------------------------------
@@ -354,20 +357,15 @@ def main():
             gossip_impl = choose_gossip_impl(fed.num_nodes, node_bytes)
         print(f"gossip-impl auto -> {gossip_impl}")
 
-    gossip_repr = args.gossip_repr
-    if gossip_repr == "auto":
-        from repro.launch.mesh import choose_gossip_repr
-
-        gossip_repr = choose_gossip_repr(fed.num_nodes, fl_cfg.comm_batch,
-                                         mesh=sweep_mesh)
-        print(f"gossip-repr auto -> {gossip_repr}")
-
     # args.mixer is already "kernel" when --use-kernel was passed (the
-    # deprecation shim above), so the flag itself stays out of the plan
+    # deprecation shim above), so the flag itself stays out of the plan;
+    # --gossip-repr auto resolves in the plan, on the mesh the mix runs on
     trainer = GluADFL(model, get_optimizer(cfg.train.optimizer, cfg.train.lr),
                       fl_cfg, mixer=args.mixer,
-                      gossip_impl=gossip_impl, gossip_repr=gossip_repr,
+                      gossip_impl=gossip_impl, gossip_repr=args.gossip_repr,
                       mesh=sweep_mesh)
+    if args.gossip_repr == "auto":
+        print(f"gossip-repr auto -> {trainer.gossip_repr}")
 
     # pre-batched validation set for the in-scan streaming eval: a capped
     # slice of every patient's val windows (one fixed array -> scan const)

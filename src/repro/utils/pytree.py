@@ -87,15 +87,19 @@ def tree_weighted_mix(stacked: PyTree, mix: jnp.ndarray) -> PyTree:
     row n holding node n's averaging weights.  Returns the mixed stacked
     tree: ``out[n] = sum_m mix[n, m] * stacked[m]``.
 
-    This is the reference (pure-jnp) implementation of the paper's gossip
-    step; the Pallas kernel in ``repro.kernels.gossip_mix`` computes the
-    same contraction blocked for VMEM.
+    This is the dense tree backend of the paper's gossip step; the
+    Pallas kernel in ``repro.kernels.gossip_mix`` computes the same
+    contraction blocked for VMEM.  The einsum runs at ``HIGHEST``
+    precision: f32-exact on the TPU, as the sparse gather-sum is (the
+    default would round every parameter to bfloat16 each round), and
+    what f32 dots already do on the CPU.
     """
 
     def mix_leaf(l: jnp.ndarray) -> jnp.ndarray:
         flat = l.reshape(l.shape[0], -1)
         mixed = jnp.einsum(
-            "nm,md->nd", mix.astype(jnp.float32), flat.astype(jnp.float32)
+            "nm,md->nd", mix.astype(jnp.float32), flat.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
         )
         return mixed.astype(l.dtype).reshape(l.shape)
 

@@ -33,6 +33,8 @@ from repro.optim import sgd
 from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the one-device auto boundary at B = 7: ONE_DEVICE_SPARSE_GOSSIP_FACTOR * 8
+CROSS = 4096
 
 
 def _run(src: str, devices: int = 8) -> str:
@@ -207,17 +209,54 @@ def test_launcher_use_kernel_shim():
 
 
 def test_choose_gossip_repr_mesh_aware():
-    # plain flop heuristic, mesh-free: boundary at factor * (B+1)
-    assert choose_gossip_repr(31, 7) == "dense"
-    assert choose_gossip_repr(32, 7) == "sparse"
+    from jax.sharding import AbstractMesh
+
+    # no mesh: the one-device crossover, FACTOR * (B+1)
+    assert choose_gossip_repr(CROSS - 1, 7) == "dense"
+    assert choose_gossip_repr(CROSS, 7) == "sparse"
+    # an explicit factor is used as given, whatever the device count
+    assert choose_gossip_repr(31, 7, factor=4) == "dense"
+    assert choose_gossip_repr(32, 7, factor=4) == "sparse"
+    # a width-1 node axis is one device too
+    mesh = make_mesh((1,), ("node",))
+    assert choose_gossip_repr(CROSS - 1, 7, mesh=mesh) == "dense"
+    assert choose_gossip_repr(CROSS, 7, mesh=mesh) == "sparse"
     # mesh path: same (N, B) flips to sparse once the per-device
     # (N/shards, N) row block outgrows the budget
-    mesh = make_mesh((1,), ("node",))
     assert choose_gossip_repr(31, 7, mesh=mesh) == "dense"
     assert choose_gossip_repr(31, 7, mesh=mesh, budget_bytes=31 * 31) == "sparse"
     # grid/model axes don't count toward the node width
     gm = make_mesh((1, 1), ("grid", "node"))
+    assert choose_gossip_repr(CROSS - 1, 7, mesh=gm) == "dense"
     assert choose_gossip_repr(31, 7, mesh=gm, budget_bytes=31 * 31) == "sparse"
+    # a node axis wider than one device keeps the 4 * (B+1) = 32 boundary
+    for wide in (AbstractMesh((4,), ("node",)), AbstractMesh((2, 2), ("grid", "node"))):
+        assert choose_gossip_repr(31, 7, mesh=wide) == "dense"
+        assert choose_gossip_repr(32, 7, mesh=wide) == "sparse"
+        assert choose_gossip_repr(226, 7, mesh=wide) == "sparse"
+    assert choose_gossip_repr(32, 7, mesh=AbstractMesh((4, 1), ("grid", "node"))) == "dense"
+
+
+@pytest.mark.multidevice
+def test_auto_repr_one_device_rule_is_the_tree_backends():
+    """Only the tree mixer takes the one-device crossover: at N=224,
+    B=7 on 8 devices it resolves dense, while the kernel mixer and both
+    sharded schedules keep the 4 * (B+1) rule and resolve sparse."""
+    out = _run(
+        """
+        from repro.core.gossip_plan import resolve_gossip_plan
+        for mixer, impl in [("sharded", "allgather"), ("tree", "allgather"),
+                            ("sharded", "gather"), ("kernel", "allgather")]:
+            plan = resolve_gossip_plan(mixer=mixer, gossip_impl=impl,
+                                       gossip_repr="auto", num_nodes=224,
+                                       comm_batch=7)
+            print(mixer, impl, plan.gossip_repr)
+        """
+    )
+    assert out.split("\n")[:4] == [
+        "sharded allgather sparse", "tree allgather dense", "sharded gather sparse",
+        "kernel allgather sparse",
+    ]
 
 
 def test_choose_gossip_impl_secure_past_budget_refuses():
@@ -231,8 +270,12 @@ def test_auto_repr_through_trainer_uses_plan_policy():
     cfg = FLConfig(topology="ring", num_nodes=226, rounds=1, comm_batch=7)
     tr = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg,
                  gossip_repr="auto")
-    assert tr.gossip_repr == "sparse"
-    assert tr.plan.gossip_repr == "sparse"
+    assert tr.gossip_repr == "dense"
+    assert tr.plan.gossip_repr == "dense"
+    # the kernel mixer keeps the 4 * (B+1) rule on one device
+    trk = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg,
+                  mixer="kernel", gossip_repr="auto")
+    assert trk.plan.gossip_repr == "sparse"
 
 
 # ---------------------------------------------------------------------------

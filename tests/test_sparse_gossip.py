@@ -27,6 +27,8 @@ from repro.optim import sgd
 from repro.utils.pytree import tree_l2_norm, tree_sub
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# the one-device auto boundary at B = 7: ONE_DEVICE_SPARSE_GOSSIP_FACTOR * 8
+CROSS = 4096
 
 
 def _run(src: str, devices: int = 8) -> str:
@@ -63,6 +65,17 @@ def test_sparse_tree_matches_dense_tree():
             np.testing.assert_array_equal(
                 np.asarray(sparse[kk])[i], np.asarray(w[kk])[i]
             )
+
+
+def test_dense_tree_gossip_lowers_at_highest_precision():
+    """The dense contraction's dots run at ``HIGHEST`` precision — f32-
+    exact on the TPU, where the default is one bfloat16 pass.  CPU f32
+    dots are exact either way, so the lowering is what shows it."""
+    w = {"a": jnp.ones((6, 3, 4)), "b": jnp.ones((6, 2))}
+    text = jax.jit(gossip_mix_tree).lower(w, jnp.eye(6)).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert len(dots) == 2
+    assert all("precision = [HIGHEST, HIGHEST]" in line for line in dots)
 
 
 # ---------------------------------------------------------------------------
@@ -186,19 +199,56 @@ def test_sparse_ring_scales_without_dense_matrix():
 def test_auto_gossip_repr_resolution():
     from repro.launch.mesh import choose_gossip_repr
 
-    assert choose_gossip_repr(226, 7) == "sparse"   # paper scale
+    # node axis on one device: dense below the dense/sparse crossover
+    assert choose_gossip_repr(226, 7) == "dense"    # paper scale
     assert choose_gossip_repr(16, 7) == "dense"     # smoke scale
-    assert choose_gossip_repr(32, 7) == "sparse"    # boundary: 4*(7+1)
-    assert choose_gossip_repr(31, 7) == "dense"
+    assert choose_gossip_repr(CROSS, 7) == "sparse"  # boundary: FACTOR*(7+1)
+    assert choose_gossip_repr(CROSS - 1, 7) == "dense"
+    assert choose_gossip_repr(32, 7) == "dense"     # the across-device 4*(7+1)
 
     cfg = FLConfig(topology="ring", num_nodes=226, rounds=1, comm_batch=7)
     tr = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg,
                  gossip_repr="auto")
-    assert tr.gossip_repr == "sparse"
+    assert tr.gossip_repr == "dense"
     cfg16 = FLConfig(topology="ring", num_nodes=16, rounds=1, comm_batch=7)
     tr16 = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg16,
                    gossip_repr="auto")
     assert tr16.gossip_repr == "dense"
+    cfg_big = FLConfig(topology="ring", num_nodes=CROSS, rounds=1, comm_batch=7)
+    tr_big = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg_big,
+                     gossip_repr="auto")
+    assert tr_big.gossip_repr == "sparse"
+
+
+def _gather_rows(trainer, n):
+    """Leading dims of every gather in the compiled round at ``n`` nodes
+    (random graph, 30% inactive)."""
+    import functools
+    import re
+
+    x, y, counts = _federation(n)
+    state = trainer.init(jax.random.PRNGKey(0))
+    round_fn = jax.jit(functools.partial(trainer._round, batch_size=4))
+    text = round_fn.lower(state, x, y, counts).compile().as_text()
+    return {int(m) for m in re.findall(r"= [a-z0-9]+\[(\d+)[\],][^=]*\bgather\(", text)}
+
+
+def test_auto_round_at_n226_has_no_neighbour_table():
+    """At the paper's N=226, B=7, ``gossip_repr="auto"`` resolves to the
+    dense contraction: the compiled round gathers no (N·(B+1), ·) rows
+    and builds no neighbour table (its (N·N,) activity gather).  The
+    explicit sparse trainer, compiled the same way, has both."""
+    n, b = N226, 7
+    cfg = FLConfig(topology="random", num_nodes=n, rounds=1, comm_batch=b,
+                   inactive_ratio=0.3)
+    auto = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg,
+                   gossip_repr="auto")
+    sparse = GluADFL(LSTMModel(hidden=4).as_model(), sgd(1e-2), cfg,
+                     gossip_repr="sparse")
+    assert auto.gossip_repr == "dense"
+    table_rows = {n * (b + 1), n * n}
+    assert not _gather_rows(auto, n) & table_rows
+    assert _gather_rows(sparse, n) >= table_rows
 
 
 def test_bad_gossip_repr_rejected():
